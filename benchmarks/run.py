@@ -107,6 +107,9 @@ def main(argv=None) -> None:
                          "phase-split (+unfused) Pallas route only")
     args = ap.parse_args(argv)
 
+    from repro.runtime.cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.list_smoke:
         for bench in list_smoke():
             print(bench)

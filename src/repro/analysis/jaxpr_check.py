@@ -28,7 +28,7 @@ import dataclasses
 from typing import Iterator
 
 import jax
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 _NARROW_FLOATS = ("float32", "bfloat16", "float16")
 

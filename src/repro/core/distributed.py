@@ -41,7 +41,7 @@ from .plan import (QuantizedMatrix, ozmm_prepared, plan_from_wire,
                    plan_to_wire, quantize_matrix, residue_products,
                    wire_bytes)
 
-from repro.launch.mesh import shard_map as _shard_map
+from repro.launch.mesh import use_mesh
 
 
 def ozmm_mn_sharded(
@@ -67,13 +67,14 @@ def ozmm_mn_sharded(
         qb = quantize_matrix(b_loc, "rhs", ms, mode=mode)
         return ozmm_prepared(qa, qb)
 
-    fn = _shard_map(
+    fn = jax.jit(jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(m_axis, None), P(None, n_axis)),
         out_specs=P(m_axis, n_axis),
-    )
-    return fn(a.astype(jnp.float64), b.astype(jnp.float64))
+    ))
+    with use_mesh(mesh):  # Explicit mesh axes need the ambient mesh
+        return fn(a.astype(jnp.float64), b.astype(jnp.float64))
 
 
 def ozmm_k_sharded(
@@ -93,8 +94,8 @@ def ozmm_k_sharded(
     int32 (D * p_max/2 * ... bounded by D * 2^31/D headroom; |partial C'_l|
     <= p_max/2 <= 544 pre-psum, so the sum <= 544 * D < 2^20 for D <= 2048).
 
-    Fast mode psums the squared norms / pmaxes the abs-maxima; accurate mode
-    pmaxes the per-row/col maxima (so every shard casts with the same global
+    Fast mode psums the squared norms / all-gathers the abs-maxima; accurate
+    mode all-gathers the per-row/col maxima (so every shard casts with the same global
     prescale), runs the round-up bound GEMM on its k-slice, and psums the f32
     partials BEFORE the (1 + k 2^-24) inflation — the Rump bound holds for
     any summation order, with the global (unsharded) k in the inflation.
@@ -105,10 +106,13 @@ def ozmm_k_sharded(
     pow2 = ms.pow2_mod_tables
     k = a.shape[1]
 
+    def gmax(x):  # exact cross-shard max; XLA:TPU has no float64 pmax
+        return jnp.max(jax.lax.all_gather(x, k_axis), axis=0)
+
     def local_fn(a_loc: jax.Array, b_loc: jax.Array) -> jax.Array:
         # --- global scaling statistics across the k shards ---
-        amax = jax.lax.pmax(jnp.max(jnp.abs(a_loc), axis=1), k_axis)
-        bmax = jax.lax.pmax(jnp.max(jnp.abs(b_loc), axis=0), k_axis)
+        amax = gmax(jnp.max(jnp.abs(a_loc), axis=1))
+        bmax = gmax(jnp.max(jnp.abs(b_loc), axis=0))
         if mode == "fast":
             sq_a = jax.lax.psum(jnp.sum(a_loc * a_loc, axis=1), k_axis)
             sq_b = jax.lax.psum(jnp.sum(b_loc * b_loc, axis=0), k_axis)
@@ -136,13 +140,19 @@ def ozmm_k_sharded(
         digits = crt.garner_digits(cs, ms)
         return crt.reconstruct(digits, ms, lmu, lnu)
 
-    fn = _shard_map(
+    # Jitted: called eagerly, shard_map would dispatch the body op by op.
+    fn = jax.jit(jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(None, k_axis), P(k_axis, None)),
         out_specs=P(),
-    )
-    return fn(a.astype(jnp.float64), b.astype(jnp.float64))
+        # The output IS replicated (gmax/psum make every shard's statistics
+        # and residues identical), but the checker cannot see through the
+        # all_gather -> max in gmax.
+        check_vma=False,
+    ))
+    with use_mesh(mesh):  # Explicit mesh axes need the ambient mesh
+        return fn(a.astype(jnp.float64), b.astype(jnp.float64))
 
 
 # ---------------------------------------------------------------------------
@@ -183,11 +193,11 @@ def _argmax_allreduce_fn(mesh: Mesh, axis: str):
         win = jnp.min(jnp.where(v == m, i, jnp.iinfo(jnp.int32).max))
         return m, win
 
-    # check_rep=False: the outputs ARE replicated (every rank gathers the same
+    # check_vma=False: the outputs ARE replicated (every rank gathers the same
     # candidates), but the static replication checker cannot see through the
     # all_gather -> max/min chain.
-    return jax.jit(_shard_map(local_fn, mesh=mesh, in_specs=(P(axis), P(axis)),
-                              out_specs=(P(), P()), check_rep=False))
+    return jax.jit(jax.shard_map(local_fn, mesh=mesh, in_specs=(P(axis), P(axis)),
+                                 out_specs=(P(), P()), check_vma=False))
 
 
 def argmax_allreduce_host(vals, idxs) -> tuple[float, int]:

@@ -202,6 +202,15 @@ def ozmm(a, b, policy=None, *, scheme: str | None = None, mode: str | None = Non
     ``+pallas`` is forward-only (the guard below raises under autodiff);
     the auto-derived route falls back to core-backed cotangent GEMMs so
     training still differentiates.
+
+    On TPU, XLA stores and computes float64 as a pair of float32s: about
+    48 significand bits and float32's exponent range. ``device_put`` already
+    rounds inputs to that format (it turns 2^1000 into inf and subnormals
+    into 0), and entries below about 2^-78 lose their low half in device
+    arithmetic. Results meet the FP64-grade bounds only where the inputs and
+    the products lie within about [2^-78, 2^126] in magnitude (zeros
+    aside); outside, they are not FP64 results, and nothing raises.
+    ``chip_smoke.py`` reports this edge on every run.
     """
     numerics.ensure_x64()
     if (scheme is not None or mode is not None or num_moduli is not None

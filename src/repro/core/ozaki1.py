@@ -29,7 +29,7 @@ class SlicedOperand(NamedTuple):
 def slice_operand(a: jax.Array, num_slices: int, axis: int) -> SlicedOperand:
     """Extract S e4m3 slices along rows (axis=0: A-side) or columns (axis=1)."""
     amax = jnp.max(jnp.abs(a), axis=1 - axis)
-    _, e = jnp.frexp(amax)  # floor(log2 amax) = e - 1
+    _, e = numerics.frexp(amax)  # floor(log2 amax) = e - 1
     base = jnp.where(amax > 0, e.astype(jnp.int32) - 1, 0)
 
     slices = []

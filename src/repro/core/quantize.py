@@ -46,10 +46,7 @@ def scaled_int(a: jax.Array, lscale: jax.Array, axis: int) -> jax.Array:
 def residues_all(a_int: jax.Array, ms: ModuliSet, pow2_tables: jax.Array) -> list[jax.Array]:
     """Centred residues of integer-valued float64 ``a_int`` for every modulus."""
     m, e = numerics.f64_to_mant_exp(a_int)
-    return [
-        numerics.residues_from_mant_exp(m, e, p, pow2_tables[l])
-        for l, p in enumerate(ms.ps)
-    ]
+    return numerics.residues(m, e, ms.ps, pow2_tables)
 
 
 def split_karatsuba(r: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:

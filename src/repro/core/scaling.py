@@ -51,8 +51,7 @@ def _clip_scale(e: jax.Array, abs_max: jax.Array) -> jax.Array:
     the denormal range legitimately need e ~ +1900 (covered by a regression
     test); likewise 1e300-range inputs need e ~ -950.
     """
-    m, emax = jnp.frexp(abs_max)
-    del m
+    _, emax = numerics.frexp(abs_max)
     cap = MAX_LOG2_SCALE - emax.astype(jnp.int32)
     e = jnp.minimum(e, cap)
     return jnp.where(abs_max > 0, e, 0).astype(jnp.int32)
@@ -98,7 +97,7 @@ def accurate_prescale(x: jax.Array, axis: int,
     does not depend on the partner matrix.
     """
     amax = jnp.max(jnp.abs(x), axis=axis) if abs_max is None else abs_max
-    _, e = jnp.frexp(amax)  # floor(log2 amax) = e - 1 for amax > 0
+    _, e = numerics.frexp(amax)  # floor(log2 amax) = e - 1 for amax > 0
     # No symmetric clamp here: denormal-range rows need lpre ~ +1010 and
     # 1e300-range rows need ~ -1000; the scaled target is 2^7 < inf either
     # way (regression: tests/core/test_ozmm_accuracy.py::test_edge_inputs).

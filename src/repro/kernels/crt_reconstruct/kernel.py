@@ -16,18 +16,28 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.moduli import KARATSUBA_S, ModuliSet
 
+from ..common import resolve_interpret
+
+
+#: Block index 0 for the index maps, as int32: a bare 0 is an int64 literal
+#: under x64, which Mosaic cannot lower.
+_I0 = np.int32(0)
+
 
 def _centered(r, p):
+    # Constants are cast to the operand dtype: a bare Python int becomes an
+    # int64 literal under x64, which Mosaic cannot lower.
     half = (p - 1) // 2
-    return r - jnp.where(r > half, p, 0).astype(r.dtype)
+    return r - jnp.where(r > half, r.dtype.type(p), r.dtype.type(0))
 
 
 def _cmod(x, p):
-    return _centered(jnp.mod(x, p), p)
+    return _centered(jnp.mod(x, x.dtype.type(p)), p)
 
 
 def _combine(c1, c2, c3, p, sq, s):
@@ -74,7 +84,7 @@ def requant_garner(
     ms: ModuliSet,
     bm: int = 128,
     bn: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """cparts: (c1, c2, c3) stacks (N, m, n) f32 for fp8 families, or a
     single-element tuple of an (N, m, n) int32 stack for int8. Returns the
@@ -82,7 +92,7 @@ def requant_garner(
     n_mod, m, n = cparts[0].shape
     assert n_mod == ms.n and m % bm == 0 and n % bn == 0
     grid = (m // bm, n // bn)
-    spec = pl.BlockSpec((n_mod, bm, bn), lambda i, j: (0, i, j))
+    spec = pl.BlockSpec((n_mod, bm, bn), lambda i, j: (_I0, i, j))
     kern = _kernel_int8 if ms.family == "int8" else _kernel_fp8
     return pl.pallas_call(
         functools.partial(kern, ms=ms),
@@ -90,5 +100,5 @@ def requant_garner(
         in_specs=[spec] * len(cparts),
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n_mod, m, n), jnp.int16),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*cparts)

@@ -19,7 +19,7 @@ def _pad3(x, m0, m1):
 
 @functools.partial(jax.jit, static_argnames=("ms", "bm", "bn", "interpret"))
 def requant_garner_op(cparts, *, ms: ModuliSet, bm: int = 128, bn: int = 128,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     m, n = cparts[0].shape[1], cparts[0].shape[2]
     padded = tuple(_pad3(c, bm, bn) for c in cparts)
     d = requant_garner(padded, ms=ms, bm=bm, bn=bn, interpret=interpret)

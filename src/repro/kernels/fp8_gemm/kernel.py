@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..common import resolve_interpret
+
 
 def _gemm_kernel(a_ref, b_ref, o_ref):
     @pl.when(pl.program_id(2) == 0)
@@ -38,7 +40,7 @@ def fp8_gemm(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """C (m, n) f32 = A (m, k) e4m3 @ B (k, n) e4m3. Dims must be multiples
     of the block shape (ops.py pads)."""
@@ -55,5 +57,5 @@ def fp8_gemm(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)

@@ -42,6 +42,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -55,38 +56,64 @@ from ..crt_reconstruct.kernel import _centered, _cmod, _combine, _garner
 
 E4M3 = jnp.float8_e4m3fn
 MANT_SPLIT = 26  # raw frame: mant = mh * 2^26 + ml (ops.decompose_raw)
+#: Block index 0 for the index maps, as int32: a bare 0 is an int64 literal
+#: under x64, which Mosaic cannot lower.
+_I0 = np.int32(0)
 
 
-def _residue_tile(mh, ml, sc, p, pw):
+def _pow2_mod(idx, p, nbits):
+    """2^idx mod p for int32 ``idx`` in [0, 2^nbits), by square-and-multiply
+    over the bits of ``idx`` with compile-time factors 2^(2^b) mod p. Every
+    product is < p^2 <= 1089^2 < 2^21, so int32 is exact; equals the
+    moduli ``pow2_mod_tables`` row entry for entry (Mosaic has no 1-D
+    gather to look the table up)."""
+    i32 = jnp.int32
+    w = jnp.ones_like(idx)
+    for b in range(nbits):
+        f = pow(2, 1 << b, p)
+        if f == 1:
+            continue
+        on = (idx & i32(1 << b)) != 0
+        w = jnp.where(on, jnp.mod(w * i32(f), i32(p)), w)
+    return w
+
+
+def _residue_tile(mh, ml, sc, p, table_len):
     """Centred residue mod ``p`` of trunc(2^sc * x) for x = (mh + ml*2^-26)
     * 2^(sc - s0) given in the sign-folded raw frame (ops.decompose_raw):
     ``sc`` is the TOTAL power-of-two exponent of the scaled value relative to
     the 53-bit integer mantissa, i.e. scaled x = (mh*2^26 + ml) * 2^sc.
 
-    All int32: negative ``sc`` truncates by logical right-shifts of the
-    magnitudes (sign is re-applied afterwards — an arithmetic shift of a
-    negative mantissa would round toward -inf, not toward zero), positive
-    ``sc`` multiplies by 2^sc mod p via the precomputed table ``pw``.
+    All int32 (constants included: under x64 a bare Python int becomes an
+    int64 literal, which Mosaic cannot lower): negative ``sc`` truncates by
+    logical right-shifts of the magnitudes (sign is re-applied afterwards —
+    an arithmetic shift of a negative mantissa would round toward -inf, not
+    toward zero), positive ``sc`` multiplies by 2^sc mod p (``_pow2_mod``).
     floor((|mh|*2^26 + |ml|) / 2^t) == |mh| >> (t - 26) for t > 26 because
     the discarded remainder is < 2^t, so the two-limb shift is exact.
     """
+    i32 = jnp.int32
+    zero = i32(0)
     amh, aml = jnp.abs(mh), jnp.abs(ml)
     sg = jnp.where(mh != 0, jnp.sign(mh), jnp.sign(ml))
-    t = jnp.maximum(-sc, 0)
-    tl = jnp.minimum(t, MANT_SPLIT)
-    th = jnp.clip(t - MANT_SPLIT, 0, 31)  # shifts >= 32 are UB; mh < 2^27
+    t = jnp.maximum(-sc, zero)
+    tl = jnp.minimum(t, i32(MANT_SPLIT))
+    th = jnp.clip(t - i32(MANT_SPLIT), zero, i32(31))  # shifts >= 32 are UB; mh < 2^27
     mh_sh = jax.lax.shift_right_logical(amh, th)
     ml_sh = jax.lax.shift_right_logical(aml, tl)
-    sp = jnp.maximum(sc, 0)
-    # Table gathers clamp to the last entry: indices only exceed the table
-    # for ZERO elements in extreme-exponent rows (scaling._clip_scale caps
-    # the scaled magnitude of nonzero values at 2^900 < 2^table_len), where
-    # the residue is 0 regardless of the gathered weight.
-    hi_cap = pw.shape[0] - 1
-    idx_h = jnp.clip(MANT_SPLIT - tl + sp, 0, hi_cap)
-    idx_l = jnp.clip(sp, 0, hi_cap)
-    r = jnp.mod(jnp.mod(mh_sh, p) * pw[idx_h] + jnp.mod(ml_sh, p) * pw[idx_l], p)
-    return _centered(jnp.mod(sg * r, p), p)
+    sp = jnp.maximum(sc, zero)
+    # Limb weights 2^(26 - tl + sp) and 2^sp mod p (tl = 0 whenever sp > 0).
+    # The core path clamps table indices to the last entry; that only
+    # matters for ZERO elements in extreme-exponent rows (scaling._clip_scale
+    # caps the scaled magnitude of nonzero values at 2^900 < 2^table_len),
+    # whose residue is 0 regardless of the weights.
+    w_l = _pow2_mod(jnp.clip(sp, zero, i32(table_len - 1)), p,
+                    (table_len - 1).bit_length())
+    w_h = jnp.mod(_pow2_mod(i32(MANT_SPLIT) - tl, p, MANT_SPLIT.bit_length()) * w_l,
+                  i32(p))
+    pc = i32(p)
+    r = jnp.mod(jnp.mod(mh_sh, pc) * w_h + jnp.mod(ml_sh, pc) * w_l, pc)
+    return _centered(jnp.mod(sg * r, pc), p)
 
 
 def _split_fp8(r, sq, s):
@@ -97,11 +124,11 @@ def _split_fp8(r, sq, s):
     f8 = lambda x: x.astype(jnp.float32).astype(E4M3)
     if sq:
         hi = jnp.round(r.astype(jnp.float32) / jnp.float32(s)).astype(jnp.int32)
-        lo = r - s * hi
+        lo = r - jnp.int32(s) * hi
         return f8(hi), f8(lo)
-    absr = jnp.abs(r)
-    hi = jnp.sign(r) * ((absr + (KARATSUBA_S - 1)) // KARATSUBA_S)
-    lo = r - KARATSUBA_S * hi
+    ks = jnp.int32(KARATSUBA_S)
+    hi = jnp.sign(r) * ((jnp.abs(r) + (ks - jnp.int32(1))) // ks)
+    lo = r - ks * hi
     return f8(hi), f8(lo), f8(hi + lo)
 
 
@@ -167,17 +194,17 @@ def _maybe_finalize(accs, lmu, lnu, out_ref, ms, reconstruct):
 
 
 def _kernel_raw(mh_a_ref, ml_a_ref, e_a_ref, lmu_ref,
-                mh_b_ref, ml_b_ref, e_b_ref, lnu_ref, tbl_ref,
+                mh_b_ref, ml_b_ref, e_b_ref, lnu_ref,
                 out_ref, *accs, ms: ModuliSet, reconstruct: str):
     """Fused schedule from raw exponent frames (on-chip quantization)."""
     _init_accs(accs)
     # Fold the pairing scale into the raw frame: scaled x = mant * 2^sc.
     s_a = e_a_ref[...] + lmu_ref[...]  # (bm, bk) + (bm, 1)
     s_b = e_b_ref[...] + lnu_ref[...]  # (bk, bn) + (1, bn)
+    table_len = ms.pow2_mod_tables.shape[1]
     for l, (p, sq, s) in enumerate(zip(ms.ps, ms.is_square, ms.split_s)):
-        pw = tbl_ref[l, :]
-        ra = _residue_tile(mh_a_ref[...], ml_a_ref[...], s_a, p, pw)
-        rb = _residue_tile(mh_b_ref[...], ml_b_ref[...], s_b, p, pw)
+        ra = _residue_tile(mh_a_ref[...], ml_a_ref[...], s_a, p, table_len)
+        rb = _residue_tile(mh_b_ref[...], ml_b_ref[...], s_b, p, table_len)
         if ms.family == "int8":
             accs[0][l] += jnp.dot(ra.astype(jnp.int8), rb.astype(jnp.int8),
                                   preferred_element_type=jnp.int32)
@@ -219,7 +246,7 @@ def _call(kern, in_specs, m, n, k, ms, bm, bn, bk, reconstruct, interpret):
         out_spec = pl.BlockSpec((bm, bn), lambda i, j, s: (i, j))
         out_shape = jax.ShapeDtypeStruct((m, n), jnp.float64)
     else:
-        out_spec = pl.BlockSpec((ms.n, bm, bn), lambda i, j, s: (0, i, j))
+        out_spec = pl.BlockSpec((ms.n, bm, bn), lambda i, j, s: (_I0, i, j))
         out_shape = jax.ShapeDtypeStruct((ms.n, m, n), jnp.int16)
     n_acc = 1 if ms.family == "int8" else 3
     return pl.pallas_call(
@@ -235,13 +262,12 @@ def _call(kern, in_specs, m, n, k, ms, bm, bn, bk, reconstruct, interpret):
 
 @functools.partial(jax.jit, static_argnames=("ms", "bm", "bn", "bk",
                                              "reconstruct", "interpret"))
-def ozmm_fused_raw(mh_a, ml_a, e_a, lmu, mh_b, ml_b, e_b, lnu, tbl, *,
+def ozmm_fused_raw(mh_a, ml_a, e_a, lmu, mh_b, ml_b, e_b, lnu, *,
                    ms: ModuliSet, bm: int, bn: int, bk: int,
                    reconstruct: str, interpret: bool):
     """Fused emulated GEMM from raw frames. Inputs: the two operands'
     sign-folded frames (int32 (m, k) / (k, n) triples, ops.decompose_raw),
-    the pairing scale exponents lmu (m, 1) / lnu (1, n) int32, and the
-    2^e-mod-p tables (N, table_len) int32. Dims must be multiples of the
+    and the pairing scale exponents lmu (m, 1) / lnu (1, n) int32. Dims must be multiples of the
     block shape (ops pads). Returns the f64 product (``reconstruct="onchip"``)
     or the int16 Garner digit stack (N, m, n) (``"xla"``)."""
     m, k = mh_a.shape
@@ -250,14 +276,13 @@ def ozmm_fused_raw(mh_a, ml_a, e_a, lmu, mh_b, ml_b, e_b, lnu, tbl, *,
         (mh_a.shape, mh_b.shape, bm, bn, bk)
     a_spec = pl.BlockSpec((bm, bk), lambda i, j, s: (i, s))
     b_spec = pl.BlockSpec((bk, bn), lambda i, j, s: (s, j))
-    lmu_spec = pl.BlockSpec((bm, 1), lambda i, j, s: (i, 0))
-    lnu_spec = pl.BlockSpec((1, bn), lambda i, j, s: (0, j))
-    tbl_spec = pl.BlockSpec(tbl.shape, lambda i, j, s: (0, 0))
+    lmu_spec = pl.BlockSpec((bm, 1), lambda i, j, s: (i, _I0))
+    lnu_spec = pl.BlockSpec((1, bn), lambda i, j, s: (_I0, j))
     call = _call(_kernel_raw,
                  [a_spec, a_spec, a_spec, lmu_spec,
-                  b_spec, b_spec, b_spec, lnu_spec, tbl_spec],
+                  b_spec, b_spec, b_spec, lnu_spec],
                  m, n, k, ms, bm, bn, bk, reconstruct, interpret)
-    return call(mh_a, ml_a, e_a, lmu, mh_b, ml_b, e_b, lnu, tbl)
+    return call(mh_a, ml_a, e_a, lmu, mh_b, ml_b, e_b, lnu)
 
 
 @functools.partial(jax.jit, static_argnames=("ms", "bm", "bn", "bk",
@@ -274,10 +299,10 @@ def ozmm_fused_parts(sa, sb, lmu, lnu, *, ms: ModuliSet, bm: int, bn: int,
         m, k = sa[0].shape[1:]
         n = sb[0].shape[2]
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k, bm, bn, bk)
-    a_spec = pl.BlockSpec((ms.n, bm, bk), lambda i, j, s: (0, i, s))
-    b_spec = pl.BlockSpec((ms.n, bk, bn), lambda i, j, s: (0, s, j))
-    lmu_spec = pl.BlockSpec((bm, 1), lambda i, j, s: (i, 0))
-    lnu_spec = pl.BlockSpec((1, bn), lambda i, j, s: (0, j))
+    a_spec = pl.BlockSpec((ms.n, bm, bk), lambda i, j, s: (_I0, i, s))
+    b_spec = pl.BlockSpec((ms.n, bk, bn), lambda i, j, s: (_I0, s, j))
+    lmu_spec = pl.BlockSpec((bm, 1), lambda i, j, s: (i, _I0))
+    lnu_spec = pl.BlockSpec((1, bn), lambda i, j, s: (_I0, j))
     if ms.family == "int8":
         call = _call(_kernel_parts_int8, [a_spec, b_spec, lmu_spec, lnu_spec],
                      m, n, k, ms, bm, bn, bk, reconstruct, interpret)

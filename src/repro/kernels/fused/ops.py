@@ -26,7 +26,7 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.core import crt, scaling
+from repro.core import crt, numerics, scaling
 from repro.core import plan as core_plan
 from repro.core.moduli import DEFAULT_NUM_MODULI, ModuliSet, make_moduli_set
 from repro.core.plan import QuantizedMatrix
@@ -39,14 +39,16 @@ from .kernel import MANT_SPLIT, ozmm_fused_parts, ozmm_fused_raw
 BLOCKS_ENV = "REPRO_FUSED_BLOCKS"
 
 #: (backend, family) -> [(max_moduli, (bm, bn, bk)), ...] — first row whose
-#: ``max_moduli`` covers the request wins. TPU rows trade bk down as the
-#: modulus count grows so the 3 int32 accumulator stacks (3*N*bm*bn*4 B,
-#: 2.25 MiB at N=12 and 128x128) plus the operand tiles stay well inside
-#: ~16 MiB VMEM; interpreter rows use smaller tiles so CI-sized problems
-#: still sweep several grid steps. ``"default"`` covers any other backend.
+#: ``max_moduli`` covers the request wins. Mosaic needs the last two block
+#: dims of every operand tile to be multiples of (8, 128), so the TPU rows
+#: keep bk = 128 at every modulus count; at N = 13 the 3 int32 accumulator
+#: stacks (3*N*bm*bn*4 B = 2.4 MiB at 128x128) plus double-buffered operand
+#: tiles stay well inside the 16 MiB of VMEM. Interpreter rows use smaller
+#: tiles so CI-sized problems still sweep several grid steps. ``"default"``
+#: covers any other backend.
 BLOCK_TABLE = {
-    ("tpu", "fp8-hybrid"): [(8, (128, 128, 128)), (99, (128, 128, 64))],
-    ("tpu", "fp8-karatsuba"): [(8, (128, 128, 128)), (99, (128, 128, 64))],
+    ("tpu", "fp8-hybrid"): [(99, (128, 128, 128))],
+    ("tpu", "fp8-karatsuba"): [(99, (128, 128, 128))],
     ("tpu", "int8"): [(99, (128, 128, 128))],
     ("interpret", "fp8-hybrid"): [(99, (64, 128, 64))],
     ("interpret", "fp8-karatsuba"): [(99, (64, 128, 64))],
@@ -94,13 +96,12 @@ def select_blocks(family: str, num_moduli: int, interpret: bool,
 
 def _preset_blocks(family: str, num_moduli: int, key: str):
     """Measured tiling from the checked-in perf presets, or None. The
-    import is deferred (and its failure tolerated) so the kernels layer
-    never hard-depends on repro.perf."""
-    try:
-        from repro.perf.model import preset_blocks
-        return preset_blocks(family, num_moduli, key)
-    except Exception:  # noqa: BLE001 — a broken preset must not break ozmm
-        return None
+    import is deferred so the kernels layer does not import repro.perf at
+    module load. Unreadable preset files are skipped where they are loaded
+    (``repro.perf.model.default_model``); any other failure is a bug and
+    raises."""
+    from repro.perf.model import preset_blocks
+    return preset_blocks(family, num_moduli, key)
 
 
 def decompose_raw(x: jax.Array):
@@ -114,7 +115,7 @@ def decompose_raw(x: jax.Array):
     accurate mode's pairing-coupled exponents apply inside the kernel, so
     the same cached frames serve any partner.
     """
-    mant, e = jnp.frexp(x)
+    mant, e = numerics.frexp(x)
     m53 = (mant * (2.0 ** 53)).astype(jnp.int64)
     e53 = (e - 53).astype(jnp.int32)
     sg = jnp.sign(m53)
@@ -154,8 +155,7 @@ def _fused_from_frames(a, lmu, b, lnu, *, ms: ModuliSet, blocks,
     fb = tuple(_pad2(v, bk, bn) for v in decompose_raw(b))
     lmu_p = _pad2(lmu[:, None], bm, 1)
     lnu_p = _pad2(lnu[None, :], 1, bn)
-    tbl = jnp.asarray(ms.pow2_mod_tables)
-    out = ozmm_fused_raw(*fa, lmu_p, *fb, lnu_p, tbl, ms=ms, bm=bm, bn=bn,
+    out = ozmm_fused_raw(*fa, lmu_p, *fb, lnu_p, ms=ms, bm=bm, bn=bn,
                          bk=bk, reconstruct=reconstruct, interpret=interpret)
     return _epilogue(out, m, n, ms, lmu, lnu, reconstruct)
 

@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..common import resolve_interpret
+
 
 def _gemm_kernel(a_ref, b_ref, o_ref):
     @pl.when(pl.program_id(2) == 0)
@@ -29,7 +31,7 @@ def int8_gemm(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     m, k = a.shape
     k2, n = b.shape
@@ -44,5 +46,5 @@ def int8_gemm(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)

@@ -19,7 +19,7 @@ def _pad_to(x: jax.Array, mult0: int, mult1: int) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def int8_gemm_op(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
-                 bk: int = 128, interpret: bool = True) -> jax.Array:
+                 bk: int = 128, interpret: bool | None = None) -> jax.Array:
     m, n = a.shape[0], b.shape[1]
     out = int8_gemm(_pad_to(a, bm, bk), _pad_to(b, bk, bn),
                     bm=bm, bn=bn, bk=bk, interpret=interpret)

@@ -26,6 +26,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.moduli import KARATSUBA_S, ModuliSet
 
+from ..common import resolve_interpret
+
 E4M3 = jnp.float8_e4m3fn
 MANT_SPLIT = 26  # mant = mh * 2^26 + ml
 
@@ -83,7 +85,7 @@ def quant_residues(
     ms: ModuliSet,
     bm: int = 128,
     bk: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Returns (hi, lo, hs) stacks (N, m, k) e4m3 for fp8 families, or a
     single (N, m, k) int8 stack for the int8 family."""
@@ -106,7 +108,7 @@ def quant_residues(
             in_specs=in_specs,
             out_specs=stack_spec,
             out_shape=jax.ShapeDtypeStruct((n, m, k), jnp.int8),
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(mh, ml, e, pow2_tables)
     return pl.pallas_call(
         functools.partial(_quant_kernel, ms=ms),
@@ -114,5 +116,5 @@ def quant_residues(
         in_specs=in_specs,
         out_specs=(stack_spec, stack_spec, stack_spec),
         out_shape=tuple(jax.ShapeDtypeStruct((n, m, k), E4M3) for _ in range(3)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(mh, ml, e, pow2_tables)

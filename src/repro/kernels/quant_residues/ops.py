@@ -27,7 +27,7 @@ def quant_residues_op(
     axis: int = 0,
     bm: int = 128,
     bk: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """A (f64) + per-row (axis=0) or per-column (axis=1) log2 scales ->
     stacked low-precision residue operands, kernel-fused over moduli."""

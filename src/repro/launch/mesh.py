@@ -2,44 +2,27 @@
 importing never touches jax device state (dry-run forces 512 host devices
 before any jax init; tests/benches must keep seeing the single real device).
 
-Also the home of the small jax-version compatibility shims the distributed
-code and tests share: ``AxisType``/``jax.set_mesh``/``jax.shard_map`` moved
-across jax releases; this container ships 0.4.x.
+Meshes are built with Auto axis types (``jax.make_mesh`` defaults to
+Explicit), which the bare-PartitionSpec sharding sites expect.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
-try:  # jax >= 0.5 re-exports shard_map at the top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - this container: jax 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-
-def _axis_type_kwargs(num_axes: int) -> dict:
-    """``axis_types=(Auto, ...)`` where the jax version has AxisType."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * num_axes}
+shard_map = jax.shard_map
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def use_mesh(mesh):
-    """Ambient-mesh context: ``jax.set_mesh`` where it exists; on jax 0.4.x
-    the ``Mesh`` object is itself the context manager. The ambient mesh
-    matters for bare-PartitionSpec ``with_sharding_constraint`` sites (e.g.
-    context-parallel attention), not just as a convenience."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is None:
-        return mesh if hasattr(mesh, "__enter__") else contextlib.nullcontext(mesh)
-    return set_mesh(mesh)
+    """Ambient-mesh context (``jax.set_mesh``). It matters for bare-
+    PartitionSpec ``with_sharding_constraint`` sites (e.g. context-parallel
+    attention) and for jitted code under a mesh with Explicit axes."""
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

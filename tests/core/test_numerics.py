@@ -1,4 +1,5 @@
 """Property tests of the exactness-critical numeric helpers (hypothesis)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -63,3 +64,93 @@ def test_two_sum():
     s, t = numerics.two_sum(a, b)
     assert float(s) + float(t) == 1e16 + 1.0 or (float(s), float(t)) == (1e16, 1.0)
     assert float(t) == (1e16 + 1.0) - float(s) or abs(float(t)) <= 1.0
+
+
+def _bits(x):
+    return np.asarray(x, np.float64).view(np.uint64)
+
+
+def _wide_range_values(seed, n=20000):
+    """Normal-range float64s across the whole exponent range plus zeros,
+    infinities and nan. Subnormal INPUTS are left out: XLA:CPU treats them
+    as zero in arithmetic (DAZ), which makes jnp.frexp return garbage."""
+    rng = np.random.default_rng(seed)
+    x = (1.0 + rng.random(n)) * np.ldexp(1.0, rng.integers(-1022, 1024, n))
+    x *= rng.choice([-1.0, 1.0], n)
+    return np.concatenate([x, [0.0, -0.0, np.inf, -np.inf, np.nan, 2.0 ** -1022,
+                               np.finfo(np.float64).max, 1.0, 0.5]])
+
+
+def test_frexp_bitwise_equals_jnp():
+    x = jnp.asarray(_wide_range_values(0))
+    m0, e0 = jnp.frexp(x)
+    m1, e1 = jax.jit(numerics.frexp_tpu)(x)
+    assert np.array_equal(_bits(m0), _bits(m1))
+    assert np.array_equal(np.asarray(e0), np.asarray(e1)) and e1.dtype == jnp.int32
+
+
+@pytest.mark.parametrize("span", [30, 1100, 2200])
+def test_ldexp2_bitwise_equals_jnp(span):
+    """Any exponent, overflowing and subnormal-range results included."""
+    x = _wide_range_values(1)
+    e = np.random.default_rng(2).integers(-span, span, x.shape[0]).astype(np.int32)
+    got = _bits(jax.jit(numerics.ldexp2_tpu)(jnp.asarray(x), jnp.asarray(e)))
+    ref = _bits(jnp.ldexp(jnp.asarray(x), jnp.asarray(e)))
+    nan = np.isnan(x)
+    assert np.array_equal(got[~nan], ref[~nan])
+
+
+def test_ldexp_wide_denormal_range_exponents():
+    """|e| ~ 1900 (denormal-range rows) via two exact halvings."""
+    x = jnp.asarray([1.5e-300, -3.0e-305, 7.0e300])
+    e = jnp.asarray([1900, 1700, -1900], jnp.int32)
+    got = numerics.ldexp_wide(x, e)
+    ref = np.ldexp(np.ldexp(np.asarray(x), np.asarray(e) // 2), np.asarray(e) - np.asarray(e) // 2)
+    assert np.array_equal(_bits(got), _bits(ref))
+    e1 = e // 2
+    tpu = jax.jit(lambda x, e: numerics.ldexp2_tpu(numerics.ldexp2_tpu(x, e1), e - e1))(x, e)
+    assert np.array_equal(_bits(tpu), _bits(ref))
+
+
+def test_residues_from_limbs_match_int64_mod():
+    rng = np.random.default_rng(3)
+    m = rng.integers(-(2 ** 53) + 1, 2 ** 53, 4096)
+    e = rng.integers(0, 1024, 4096).astype(np.int32)
+    for p in (1089, 1024, 511, 256, 199):
+        table = jnp.asarray([pow(2, j, p) for j in range(1024)], jnp.int32)
+        mh, ml = numerics.mant_limbs(jnp.asarray(m, jnp.int64))
+        got = np.asarray(numerics.residues_from_limbs(mh, ml, jnp.asarray(e), p, table))
+        want = np.asarray([(int(v) * pow(2, int(s), p)) % p for v, s in zip(m, e)])
+        want = np.where(want > (p - 1) // 2, want - p, want)
+        assert np.array_equal(got, want), p
+        tpu = numerics.residues_tpu(jnp.asarray(m, jnp.int64), jnp.asarray(e), [p], table[None])
+        assert np.array_equal(np.asarray(tpu[0]), want), p
+        assert np.array_equal(np.asarray(numerics.residues_from_mant_exp(
+            jnp.asarray(m, jnp.int64), jnp.asarray(e), p, table)), want), p
+
+
+def test_tpu_formulations_give_the_same_ozmm_bits(monkeypatch):
+    """The whole emulated GEMM with the XLA:TPU formulations of frexp,
+    ldexp and the residues, run here on the CPU, is bitwise equal to the
+    default one, rows that need scale exponents beyond 1023 and zero rows
+    included (the residues of every family's moduli are pinned above)."""
+    from repro.core import ozmm
+
+    rng = np.random.default_rng(4)
+    a = rng.standard_normal((24, 40))
+    b = rng.standard_normal((40, 16))
+    a[3] = np.abs(a[3]) * 1e-307 + 1e-307
+    a[5] *= 1e290
+    a[7] = 0.0
+    spec = "ozaki2-fp8/accurate"
+    want = np.asarray(ozmm(jnp.asarray(a), jnp.asarray(b), spec))
+    monkeypatch.setattr(numerics, "frexp", numerics.frexp_tpu)
+    monkeypatch.setattr(numerics, "ldexp2", numerics.ldexp2_tpu)
+    monkeypatch.setattr(numerics, "residues", numerics.residues_tpu)
+    jax.clear_caches()  # traces of the default formulations are cached
+    try:
+        got = np.asarray(ozmm(jnp.asarray(a), jnp.asarray(b), spec))
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert np.array_equal(_bits(got), _bits(want))

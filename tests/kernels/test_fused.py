@@ -215,3 +215,16 @@ def test_auto_backend_bwd_falls_back_to_core(rng):
                           pol.num_slices)
     np.testing.assert_array_equal(np.asarray(ga), np.asarray(ga_ref))
     np.testing.assert_array_equal(np.asarray(gb), np.asarray(gb_ref))
+
+
+@pytest.mark.parametrize("family", ["fp8-hybrid", "fp8-karatsuba", "int8"])
+def test_in_kernel_pow2_mod_equals_table(family):
+    """The kernel's square-and-multiply 2^s mod p reproduces the moduli
+    tables that the core path gathers from, for every table index."""
+    from repro.kernels.fused.kernel import _pow2_mod
+
+    ms = make_moduli_set(family, 12)
+    idx = jnp.arange(ms.pow2_mod_tables.shape[1], dtype=jnp.int32)
+    for l, p in enumerate(ms.ps):
+        got = np.asarray(_pow2_mod(idx, p, 10))
+        assert np.array_equal(got, ms.pow2_mod_tables[l]), p

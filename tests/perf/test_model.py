@@ -240,5 +240,9 @@ class TestSelectBlocksIntegration:
             def fresh(self, *_):
                 return True
 
+        # Corrupt preset FILES are skipped by default_model (test_presets);
+        # a model that fails while it is consulted is a bug and must raise
+        # instead of silently falling back to the static table.
         set_default_model(Exploding())
-        assert _preset_blocks("fp8-hybrid", 4, "interpret") is None
+        with pytest.raises(RuntimeError, match="corrupt"):
+            _preset_blocks("fp8-hybrid", 4, "interpret")
