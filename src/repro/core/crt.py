@@ -48,6 +48,8 @@ def combine_residue_product(
     return centered_mod(t, p)
 
 
+@jax.named_scope("ozmm.reconstruct")
+@jax.named_scope("ozmm.garner")
 def garner_digits(cs: list[jax.Array], ms: ModuliSet) -> jax.Array:
     """Balanced mixed-radix digits from centred residues.
 
@@ -68,6 +70,7 @@ def garner_digits(cs: list[jax.Array], ms: ModuliSet) -> jax.Array:
     return jnp.stack(digits)
 
 
+@jax.named_scope("ozmm.reconstruct")
 def reconstruct(
     digits: jax.Array, ms: ModuliSet, lmu: jax.Array, lnu: jax.Array
 ) -> jax.Array:

@@ -167,6 +167,7 @@ def quantize_matrix(
 
 
 @functools.partial(jax.jit, static_argnames=("role", "ms", "mode"))
+@jax.named_scope("ozmm.quantize")
 def _quantize_matrix_jit(
     x: jax.Array,
     role: str,
@@ -213,6 +214,7 @@ def transpose_plan(q: QuantizedMatrix) -> QuantizedMatrix:
                            stats=q.stats.transpose())
 
 
+@jax.named_scope("ozmm.products")
 def residue_products(qa, qb, ms: ModuliSet) -> list[jax.Array]:
     """Run the low-precision GEMM schedule; return centred residues C'_l.
 
@@ -352,6 +354,7 @@ def _check_pair(qa: QuantizedMatrix, qb: QuantizedMatrix) -> ModuliSet:
     return qa.ms
 
 
+@jax.named_scope("ozmm.scaling")
 def pair_exponents(qa: QuantizedMatrix, qb: QuantizedMatrix):
     """Scale exponents (lmu, lnu) of the pairing — cached in fast mode; the
     single bound GEMM between the cached round-up casts (paper §III-E) in
@@ -380,8 +383,9 @@ def pair_scales(qa: QuantizedMatrix, qb: QuantizedMatrix):
     if qa.mode == "fast":
         return lmu, lnu, qa.parts, qb.parts
     pow2 = jnp.asarray(ms.pow2_mod_tables)
-    parts_a = quantize.quantize_operand(qa.x, lmu, 0, ms, pow2).parts
-    parts_b = quantize.quantize_operand(qb.x, lnu, 1, ms, pow2).parts
+    with jax.named_scope("ozmm.quantize"):
+        parts_a = quantize.quantize_operand(qa.x, lmu, 0, ms, pow2).parts
+        parts_b = quantize.quantize_operand(qb.x, lnu, 1, ms, pow2).parts
     return lmu, lnu, parts_a, parts_b
 
 

@@ -154,6 +154,7 @@ def scaling_accurate(a: jax.Array, b: jax.Array, ms: ModuliSet) -> ScalingResult
     return ScalingResult(lmu, lnu, 1)
 
 
+@jax.named_scope("ozmm.scaling")
 def compute_scaling(a: jax.Array, b: jax.Array, ms: ModuliSet, mode: str) -> ScalingResult:
     if mode == "fast":
         return scaling_fast(a, b, ms)

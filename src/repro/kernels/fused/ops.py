@@ -104,6 +104,7 @@ def _preset_blocks(family: str, num_moduli: int, key: str):
     return preset_blocks(family, num_moduli, key)
 
 
+@jax.named_scope("ozmm.quantize")
 def decompose_raw(x: jax.Array):
     """f64 -> sign-folded two-limb raw frame: x = (mh*2^26 + ml) * 2^e with
     mh, ml, e int32, sign carried by BOTH limbs (|mh| < 2^27, |ml| < 2^26).
@@ -155,8 +156,11 @@ def _fused_from_frames(a, lmu, b, lnu, *, ms: ModuliSet, blocks,
     fb = tuple(_pad2(v, bk, bn) for v in decompose_raw(b))
     lmu_p = _pad2(lmu[:, None], bm, 1)
     lnu_p = _pad2(lnu[None, :], 1, bn)
-    out = ozmm_fused_raw(*fa, lmu_p, *fb, lnu_p, ms=ms, bm=bm, bn=bn,
-                         bk=bk, reconstruct=reconstruct, interpret=interpret)
+    # Scoped here, outside the kernel's jit: a scope inside it would rename
+    # the kernel's HLO instruction.
+    with jax.named_scope("ozmm.products"):
+        out = ozmm_fused_raw(*fa, lmu_p, *fb, lnu_p, ms=ms, bm=bm, bn=bn,
+                             bk=bk, reconstruct=reconstruct, interpret=interpret)
     return _epilogue(out, m, n, ms, lmu, lnu, reconstruct)
 
 
@@ -177,8 +181,9 @@ def _fused_from_parts(sa, lmu, sb, lnu, *, ms: ModuliSet, blocks,
           else tuple(_pad3(v, bk, bn) for v in sb))
     lmu_p = _pad2(lmu[:, None], bm, 1)
     lnu_p = _pad2(lnu[None, :], 1, bn)
-    out = ozmm_fused_parts(pa, pb, lmu_p, lnu_p, ms=ms, bm=bm, bn=bn, bk=bk,
-                           reconstruct=reconstruct, interpret=interpret)
+    with jax.named_scope("ozmm.products"):
+        out = ozmm_fused_parts(pa, pb, lmu_p, lnu_p, ms=ms, bm=bm, bn=bn, bk=bk,
+                               reconstruct=reconstruct, interpret=interpret)
     return _epilogue(out, m, n, ms, lmu, lnu, reconstruct)
 
 

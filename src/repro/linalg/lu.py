@@ -17,17 +17,24 @@ substitution scans behind the TRSM diagonal blocks) are the grid-agnostic
 block ops of ``blocks.py``, shared with ``repro.linalg.dist`` — which is
 what makes the block-cyclic factorization AND its distributed
 triangular-solve epilogue bitwise-equal to this path in fast mode.
+
+Spans (``repro.obs``, on the JAX profiler's clock): ``linalg.lu.factor``
+over the whole factorization and, per block step ``step=k0``,
+``linalg.lu.panel`` (the column loop), ``linalg.lu.trsm`` (U12) and
+``linalg.lu.update`` (the trailing update with its host copy).
 """
 from __future__ import annotations
 
 import numpy as np
 
 from repro.core import resolve_policy
+from repro.obs import span
 
 from .blas3 import DEFAULT_BLOCK, device_matmul, gemm, prepare, trsm
 from .blocks import pivot_argmax, rank1_update, scale_pivot_column
 
 
+@span("linalg.lu.factor")
 def lu_factor(a, policy=None, *, block: int = DEFAULT_BLOCK
               ) -> tuple[np.ndarray, np.ndarray]:
     """Factor square A with partial pivoting: ``A[perm] = L @ U``.
@@ -50,33 +57,36 @@ def lu_factor(a, policy=None, *, block: int = DEFAULT_BLOCK
         # so the packed storage stays consistent. The pivot search runs on
         # device (blocks.pivot_argmax); the O(n·b^2) updates are host work
         # shared with the block-cyclic path (blocks.py).
-        for j in range(k0, k1):
-            off, mag = pivot_argmax(a[j:, j])
-            p = j + off
-            if mag == 0.0:
-                raise np.linalg.LinAlgError(f"singular: zero pivot column {j}")
-            if p != j:
-                a[[j, p]] = a[[p, j]]
-                perm[[j, p]] = perm[[p, j]]
-            a[j + 1:, j] = scale_pivot_column(a[j + 1:, j], a[j, j])
-            rank1_update(a[j + 1:, j + 1:k1], a[j + 1:, j], a[j, j + 1:k1])
+        with span("linalg.lu.panel", step=k0):
+            for j in range(k0, k1):
+                off, mag = pivot_argmax(a[j:, j])
+                p = j + off
+                if mag == 0.0:
+                    raise np.linalg.LinAlgError(f"singular: zero pivot column {j}")
+                if p != j:
+                    a[[j, p]] = a[[p, j]]
+                    perm[[j, p]] = perm[[p, j]]
+                a[j + 1:, j] = scale_pivot_column(a[j + 1:, j], a[j, j])
+                rank1_update(a[j + 1:, j + 1:k1], a[j + 1:, j], a[j, j + 1:k1])
         if k1 == n:
             break
         # U12 := L11^{-1} A12 — blocked TRSM (GEMM-backed for wide panels)
-        a[k0:k1, k1:] = trsm(a[k0:k1, k0:k1], a[k0:k1, k1:], pol,
-                             side="left", lower=True, unit_diag=True,
-                             block=block)
+        with span("linalg.lu.trsm", step=k0):
+            a[k0:k1, k1:] = trsm(a[k0:k1, k0:k1], a[k0:k1, k1:], pol,
+                                 side="left", lower=True, unit_diag=True,
+                                 block=block)
         # trailing update A22 -= L21 @ U12: THE emulated DGEMM of the step.
         # One GEMM already quantizes each panel exactly once — tiling it
         # would only multiply dispatches — so the plan path's job here is
         # keeping the prepared panel device-resident; the per-step REUSE in
         # blocked LU lives in the TRSM above (solved U12 block-rows).
-        if pol.plans_enabled:
-            l21 = prepare(a[k1:, k0:k1], "lhs", pol)
-            a[k1:, k1:] -= np.asarray(device_matmul(l21, a[k0:k1, k1:], pol))
-        else:
-            a[k1:, k1:] = gemm(a[k1:, k0:k1], a[k0:k1, k1:], pol,
-                               alpha=-1.0, beta=1.0, c=a[k1:, k1:])
+        with span("linalg.lu.update", step=k0):
+            if pol.plans_enabled:
+                l21 = prepare(a[k1:, k0:k1], "lhs", pol)
+                a[k1:, k1:] -= np.asarray(device_matmul(l21, a[k0:k1, k1:], pol))
+            else:
+                a[k1:, k1:] = gemm(a[k1:, k0:k1], a[k0:k1, k1:], pol,
+                                   alpha=-1.0, beta=1.0, c=a[k1:, k1:])
     return a, perm
 
 

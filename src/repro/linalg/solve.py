@@ -10,6 +10,10 @@ Condition-aware precision (repro.precision.resolve): pass
 ``target_rel_err=`` and the solve resolves its ``num_moduli`` from the
 system matrix's exponent-range sketch before factoring — the ROADMAP's
 "condition-number-aware num_moduli selection per solve".
+
+Spans (``repro.obs``, on the JAX profiler's clock): ``linalg.refine_solve``
+over the whole call, ``linalg.lu.solve`` over each LU solve and
+``linalg.refine.residual`` over each residual product.
 """
 from __future__ import annotations
 
@@ -18,6 +22,7 @@ import dataclasses
 import numpy as np
 
 from repro.core import resolve_policy
+from repro.obs import span
 
 from .blas3 import DEFAULT_BLOCK, emulated_matmul, trsm
 from .cholesky import cholesky
@@ -31,6 +36,7 @@ def _as_cols(b) -> tuple[np.ndarray, bool]:
     return b, False
 
 
+@span("linalg.lu.solve")
 def lu_solve(lu: np.ndarray, perm: np.ndarray, b, policy=None, *,
              block: int = DEFAULT_BLOCK) -> np.ndarray:
     """Solve A x = b given ``(lu, perm)`` from :func:`repro.linalg.lu_factor`.
@@ -58,6 +64,13 @@ def cholesky_solve(l_fac: np.ndarray, b, policy=None, *,
     return x[:, 0] if was_vec else x
 
 
+@span("linalg.refine.residual")
+def _residual(a, x, rhs, res_pol) -> np.ndarray:
+    """``rhs - A x`` with the product on ``res_pol``."""
+    return rhs - emulated_matmul(a, x, res_pol)
+
+
+@span("linalg.refine_solve")
 def refine_solve(a, b, policy=None, *, factor: str = "lu",
                  refine_steps: int = 2, block: int = DEFAULT_BLOCK,
                  residual_policy=None, target_rel_err: float | None = None
@@ -96,10 +109,10 @@ def refine_solve(a, b, policy=None, *, factor: str = "lu",
     x = solve(rhs)
     residuals = []
     for _ in range(refine_steps):
-        r = rhs - emulated_matmul(a, x, res_pol)
+        r = _residual(a, x, rhs, res_pol)
         residuals.append(float(np.linalg.norm(r, np.inf)) / scale)
         x = x + solve(r)
-    r = rhs - emulated_matmul(a, x, res_pol)
+    r = _residual(a, x, rhs, res_pol)
     residuals.append(float(np.linalg.norm(r, np.inf)) / scale)
     info = {"residuals": residuals, "refine_steps": refine_steps,
             "factor": factor, "scheme": pol.scheme,

@@ -8,7 +8,10 @@ caches:
   context manager + decorator, contextvar parent linking, explicit
   ``sp.fence(x)`` device fencing (``jax.block_until_ready`` before the end
   timestamp). Spans always time — legacy stats dicts read ``sp.elapsed`` —
-  and record into the trace buffer only while tracing is enabled.
+  and always hold a ``jax.profiler.TraceAnnotation`` of their name, so a
+  profiler trace shows them on the host plane; they record into the trace
+  buffer only while tracing is enabled. Garbage collections show in a
+  profiler trace as ``python.gc`` annotations.
 * **Metrics** (:mod:`.metrics`): counters/gauges/histograms in a flat
   registry; module-level gated emitters for global instrumentation (no-ops
   that allocate nothing when disabled — the ``ozmm`` hot-path contract) and

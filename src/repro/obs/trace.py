@@ -20,6 +20,13 @@ Design constraints (ISSUE 9):
   id, and the linkage survives threads and (trivially) asyncio tasks. The
   contextvar is touched only when tracing is enabled, so the disabled path
   stays near-zero-cost.
+* **On the profiler's clock**: for its whole extent a span also holds a
+  ``jax.profiler.TraceAnnotation`` of the same name, with its attributes
+  as the annotation's keyword arguments (so the event keeps exactly the
+  span's name). It does so whether or not tracing is enabled: the
+  annotation costs about a microsecond and records only while a JAX
+  profiler session collects, where the span lands on the host plane
+  beside the device's operations.
 * **Device fencing**: JAX dispatch is asynchronous — a span closing right
   after ``jit_fn(x)`` measures dispatch, not compute. ``sp.fence(value)``
   calls ``jax.block_until_ready`` on the value (any pytree) before the end
@@ -29,16 +36,23 @@ Design constraints (ISSUE 9):
 
 The recorder is process-global and thread-safe (append under a lock); export
 formats live in :mod:`repro.obs.export`.
+
+Importing this module also installs a ``gc.callbacks`` hook that holds a
+``python.gc`` annotation (with the collection's ``generation``) for every
+garbage collection, so a profiler trace shows the collector's pauses.
 """
 from __future__ import annotations
 
 import contextvars
 import functools
+import gc
 import itertools
 import os
 import threading
 import time
 from typing import Any, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "span", "tracing_enabled", "enable_tracing",
            "disable_tracing", "clear_trace", "trace_events", "TRACE_CLOCK"]
@@ -80,11 +94,12 @@ def trace_events() -> list[dict]:
 
 
 class Span:
-    """One timed phase. Always measures ``elapsed``; records into the trace
-    buffer (with parent linkage) only while tracing is enabled."""
+    """One timed phase. Always measures ``elapsed`` and holds a profiler
+    annotation of its name; records into the trace buffer (with parent
+    linkage) only while tracing is enabled."""
 
     __slots__ = ("name", "attrs", "_t0", "_t1", "_id", "_parent", "_token",
-                 "_recording")
+                 "_recording", "_annotation")
 
     def __init__(self, name: str, attrs: Optional[dict] = None):
         self.name = name
@@ -96,6 +111,8 @@ class Span:
 
     # -- context manager -------------------------------------------------
     def __enter__(self) -> "Span":
+        self._annotation = TraceAnnotation(self.name, **(self.attrs or {}))
+        self._annotation.__enter__()
         self._recording = _ENABLED
         if self._recording:
             self._id = next(_IDS)
@@ -119,6 +136,7 @@ class Span:
                 event["error"] = exc_type.__name__
             with _EVENTS_LOCK:
                 _EVENTS.append(event)
+        self._annotation.__exit__(exc_type, exc, tb)
 
     # -- decorator form ---------------------------------------------------
     def __call__(self, fn):
@@ -148,14 +166,25 @@ class Span:
         ``__exit__``; call sites feed this into legacy stats dicts."""
         return self._t1 - self._t0
 
-    def set_attrs(self, **attrs) -> None:
-        """Attach attributes after entry (e.g. sizes known only mid-phase)."""
-        if self._recording:
-            if self.attrs is None:
-                self.attrs = {}
-            self.attrs.update(attrs)
-
 
 def span(name: str, **attrs) -> Span:
     """Create a span — use as ``with span("x"): ...`` or ``@span("x")``."""
     return Span(name, attrs or None)
+
+
+#: The annotation of the collection in progress (collections do not nest).
+_GC_ANNOTATION: Optional[TraceAnnotation] = None
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: a ``python.gc`` annotation over each collection."""
+    global _GC_ANNOTATION
+    if phase == "start":
+        _GC_ANNOTATION = TraceAnnotation("python.gc", generation=info["generation"])
+        _GC_ANNOTATION.__enter__()
+    elif _GC_ANNOTATION is not None:
+        _GC_ANNOTATION.__exit__(None, None, None)
+        _GC_ANNOTATION = None
+
+
+gc.callbacks.append(_gc_span)

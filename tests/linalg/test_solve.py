@@ -68,3 +68,41 @@ def test_hpl_scaled_residual_metric():
     x = np.linalg.solve(a, b)
     assert hpl_scaled_residual(a, x, b) <= HPL_THRESHOLD
     assert hpl_scaled_residual(a, np.zeros_like(x), b) > HPL_THRESHOLD
+
+
+def test_refine_solve_spans_cover_the_solve(tmp_path):
+    """Under a CPU profiler trace, ``refine_solve`` at n=256, NB=64 with one
+    refinement step puts its phases on the host plane: a panel per block
+    step, a U12 TRSM and a trailing update per step but the last, an LU
+    solve and a residual per round. Together they cover >= 90% of the call.
+    (The shapes and policy of ``test_hpl_gate`` above, whose programs this
+    process has compiled already.)"""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    a, b = hpl_matrix(256, seed=2)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        refine_solve(a, b, EMU, factor="lu", refine_steps=1, block=64)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    total: dict[str, float] = {}
+    count: dict[str, int] = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("linalg."):
+                        total[e.name] = total.get(e.name, 0.0) + e.duration_ns
+                        count[e.name] = count.get(e.name, 0) + 1
+    assert count == {"linalg.refine_solve": 1, "linalg.lu.factor": 1,
+                     "linalg.lu.panel": 4, "linalg.lu.trsm": 3, "linalg.lu.update": 3,
+                     "linalg.lu.solve": 2, "linalg.refine.residual": 2}
+    phases = ("linalg.lu.panel", "linalg.lu.trsm", "linalg.lu.update",
+              "linalg.lu.solve", "linalg.refine.residual")
+    assert sum(total[p] for p in phases) >= 0.9 * total["linalg.refine_solve"]
