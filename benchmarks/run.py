@@ -101,7 +101,7 @@ def main(argv=None) -> None:
     kp = ap.add_mutually_exclusive_group()
     kp.add_argument("--fused", dest="fused", action="store_true", default=None,
                     help="kernel-path benches: compare core vs the fused "
-                         "single-kernel Pallas route only")
+                         "Pallas route only")
     kp.add_argument("--unfused", dest="fused", action="store_false",
                     help="kernel-path benches: compare core vs the "
                          "phase-split (+unfused) Pallas route only")

@@ -255,7 +255,7 @@ def _resolve_backend(pol: PrecisionPolicy, device: str | None = None) -> str:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _ozmm_pallas_guarded(a, b, pol):
-    """Kernel-path forward (fused single-kernel schedule by default,
+    """Kernel-path forward (fused schedule by default,
     phase-split pipeline under ``+unfused``). The quantization kernels are
     trunc/mod (zero-derivative a.e.), so naive autodiff through them would
     silently return all-zero gradients — this custom VJP intercepts:

@@ -6,14 +6,15 @@ in interpret=True mode against the oracle across shape/dtype sweeps.
 
 Two executors share the packages: the phase-split ``pipeline`` (one
 pallas_call per phase; residue parts/products/digits round-trip HBM) and
-the single-kernel ``fused`` schedule (quantize -> residue MMA -> Garner
-reconstruct without leaving the chip — the `+pallas` default route).
+the ``fused`` schedule (each operand quantized once to residue parts, then
+one kernel for residue MMA -> Garner reconstruct — the `+pallas` default
+route).
 """
 from .common import resolve_interpret, resolve_reconstruct, stack_parts
 from .crt_reconstruct import reconstruct_f64, requant_garner, requant_garner_op, requant_garner_ref
 from .fp8_gemm import fp8_gemm, fp8_gemm_op, fp8_gemm_ref
 from .fused import (BLOCK_TABLE, decompose_raw, fused_digits_ref,
-                    ozmm_fused_parts, ozmm_fused_raw, ozmm_fused_ref,
+                    ozmm_fused_parts, ozmm_fused_quantize, ozmm_fused_ref,
                     ozmm_pallas_fused, ozmm_pallas_fused_prepared,
                     select_blocks)
 from .int8_gemm import int8_gemm, int8_gemm_op, int8_gemm_ref
@@ -27,7 +28,7 @@ __all__ = [
     "requant_garner", "requant_garner_op", "requant_garner_ref", "reconstruct_f64",
     "ozmm_pallas", "ozmm_pallas_prepared",
     "ozmm_pallas_fused", "ozmm_pallas_fused_prepared",
-    "ozmm_fused_raw", "ozmm_fused_parts", "ozmm_fused_ref", "fused_digits_ref",
+    "ozmm_fused_quantize", "ozmm_fused_parts", "ozmm_fused_ref", "fused_digits_ref",
     "decompose_raw", "select_blocks", "BLOCK_TABLE",
     "resolve_interpret", "resolve_reconstruct", "stack_parts",
 ]

@@ -1,19 +1,23 @@
-"""Pallas TPU kernel: the whole Ozaki-II emulated GEMM in ONE ``pallas_call``.
+"""Pallas TPU kernels: the Ozaki-II emulated GEMM in two ``pallas_call``s.
 
 The phase-split pipeline (``kernels/pipeline.py``) materializes every
 intermediate in HBM: residue-part stacks after quantization, N (or 3N)
-per-modulus GEMM outputs, the digit stack after requant. EmuGEMM-style
-fusion collapses all of it into a single k-innermost blocked schedule — per
-(bm, bn) output tile and per k step this kernel:
+per-modulus GEMM outputs, the digit stack after requant. The fused schedule
+keeps only the residue parts:
 
-  1. quantizes the A and B k-tiles to centred residues on-chip, folding the
-     ``quant_residues`` exponent-frame math into the tile loop (the f64 ->
-     raw-frame decomposition stays in XLA, see ops.decompose_raw; applying
-     the pairing scale 2^l and the truncation is pure int32 shift/mod
-     arithmetic, done here);
-  2. splits the residues and issues the eq. (8)/(12) FP8 MMA schedule (or
-     the single int8 MMA) straight from VMEM;
-  3. accumulates the per-modulus partial products into int32 VMEM scratch.
+  1. ``ozmm_fused_quantize`` (once per operand, a 2-D grid over its tiles)
+     quantizes each tile to centred residues, folding the ``quant_residues``
+     exponent-frame math into the tile loop (the f64 -> raw-frame
+     decomposition stays in XLA, see ops.decompose_raw; applying the pairing
+     scale 2^l and the truncation is pure int32 shift/mod arithmetic, done
+     here), and splits them into e4m3 parts (or int8 residues). A residue
+     of A depends only on A and its row exponents, one of B only on B and
+     its column exponents, so each tile is quantized once however many
+     partner tiles it meets.
+  2. ``ozmm_fused_parts`` runs a k-innermost blocked schedule: per (bm, bn)
+     output tile and per k step it issues the eq. (8)/(12) FP8 MMA schedule
+     (or the single int8 MMA) on the streamed part tiles and accumulates
+     the per-modulus partial products into int32 VMEM scratch.
 
 At the last k step the scratch accumulators run the residue combine +
 balanced Garner digits (identical int32 arithmetic to ``crt_reconstruct`` —
@@ -33,8 +37,9 @@ epilogue performs the same Kahan scan + ldexp_wide in the same order.
 Accumulator bounds: fp8 families |c| <= k * 2^9  (k <= 2^21 fits int32; the
 f32 partial-dot exactness already requires bk*2^9 <= 2^24); int8 family
 |c| <= k * 2^14 (k <= 2^16). VMEM budget at (128, 128) tiles, N = 12:
-3 accumulators x 12 x 128 x 128 x 4 B = 2.25 MiB + ~400 KiB operand tiles —
-comfortably inside ~16 MiB (docs/kernels.md has the full budget table).
+3 accumulators x 12 x 128 x 128 x 4 B = 2.25 MiB + 2 x 1.125 MiB of
+double-buffered part tiles — comfortably inside ~16 MiB (docs/kernels.md
+has the full budget table, and the parts' HBM footprint).
 """
 from __future__ import annotations
 
@@ -193,33 +198,30 @@ def _maybe_finalize(accs, lmu, lnu, out_ref, ms, reconstruct):
         _finalize(accs, lmu, lnu, out_ref, ms, reconstruct)
 
 
-def _kernel_raw(mh_a_ref, ml_a_ref, e_a_ref, lmu_ref,
-                mh_b_ref, ml_b_ref, e_b_ref, lnu_ref,
-                out_ref, *accs, ms: ModuliSet, reconstruct: str):
-    """Fused schedule from raw exponent frames (on-chip quantization)."""
-    _init_accs(accs)
+def _kernel_quantize(mh_ref, ml_ref, e_ref, l_ref, *out_refs, ms: ModuliSet):
+    """One operand tile -> its residue parts for every modulus, in the
+    ``common.stack_parts`` layout: fp8 families write (hi, lo, hs) e4m3
+    stacks (hs zero for square moduli), int8 one int8 stack."""
     # Fold the pairing scale into the raw frame: scaled x = mant * 2^sc.
-    s_a = e_a_ref[...] + lmu_ref[...]  # (bm, bk) + (bm, 1)
-    s_b = e_b_ref[...] + lnu_ref[...]  # (bk, bn) + (1, bn)
+    sc = e_ref[...] + l_ref[...]  # (br, bc) + (br, 1) for A, (1, bc) for B
     table_len = ms.pow2_mod_tables.shape[1]
     for l, (p, sq, s) in enumerate(zip(ms.ps, ms.is_square, ms.split_s)):
-        ra = _residue_tile(mh_a_ref[...], ml_a_ref[...], s_a, p, table_len)
-        rb = _residue_tile(mh_b_ref[...], ml_b_ref[...], s_b, p, table_len)
+        r = _residue_tile(mh_ref[...], ml_ref[...], sc, p, table_len)
         if ms.family == "int8":
-            accs[0][l] += jnp.dot(ra.astype(jnp.int8), rb.astype(jnp.int8),
-                                  preferred_element_type=jnp.int32)
-        else:
-            for acc, d in zip(accs, _mma_fp8(_split_fp8(ra, sq, s),
-                                             _split_fp8(rb, sq, s), sq)):
-                acc[l] += d
-    _maybe_finalize(accs, lmu_ref[...], lnu_ref[...], out_ref, ms, reconstruct)
+            out_refs[0][l] = r.astype(jnp.int8)
+            continue
+        parts = _split_fp8(r, sq, s)
+        if sq:
+            parts += (jnp.zeros_like(parts[0]),)
+        for ref, v in zip(out_refs, parts):
+            ref[l] = v
 
 
 def _kernel_parts_fp8(a_hi_ref, a_lo_ref, a_hs_ref, b_hi_ref, b_lo_ref,
                       b_hs_ref, lmu_ref, lnu_ref, out_ref, *accs,
                       ms: ModuliSet, reconstruct: str):
-    """Fused MMA + reconstruct from prepared residue parts (fast-mode plans:
-    the quantization phase was cached, digits stream straight through)."""
+    """Fused MMA + reconstruct from residue parts (``_kernel_quantize``'s, or
+    the cached ones of fast-mode plans)."""
     _init_accs(accs)
     for l, sq in enumerate(ms.is_square):
         pa = (a_hi_ref[l], a_lo_ref[l]) if sq else (a_hi_ref[l], a_lo_ref[l], a_hs_ref[l])
@@ -260,29 +262,34 @@ def _call(kern, in_specs, m, n, k, ms, bm, bn, bk, reconstruct, interpret):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("ms", "bm", "bn", "bk",
-                                             "reconstruct", "interpret"))
-def ozmm_fused_raw(mh_a, ml_a, e_a, lmu, mh_b, ml_b, e_b, lnu, *,
-                   ms: ModuliSet, bm: int, bn: int, bk: int,
-                   reconstruct: str, interpret: bool):
-    """Fused emulated GEMM from raw frames. Inputs: the two operands'
-    sign-folded frames (int32 (m, k) / (k, n) triples, ops.decompose_raw),
-    and the pairing scale exponents lmu (m, 1) / lnu (1, n) int32. Dims must be multiples of the
-    block shape (ops pads). Returns the f64 product (``reconstruct="onchip"``)
-    or the int16 Garner digit stack (N, m, n) (``"xla"``)."""
-    m, k = mh_a.shape
-    k2, n = mh_b.shape
-    assert k == k2 and m % bm == 0 and n % bn == 0 and k % bk == 0, \
-        (mh_a.shape, mh_b.shape, bm, bn, bk)
-    a_spec = pl.BlockSpec((bm, bk), lambda i, j, s: (i, s))
-    b_spec = pl.BlockSpec((bk, bn), lambda i, j, s: (s, j))
-    lmu_spec = pl.BlockSpec((bm, 1), lambda i, j, s: (i, _I0))
-    lnu_spec = pl.BlockSpec((1, bn), lambda i, j, s: (_I0, j))
-    call = _call(_kernel_raw,
-                 [a_spec, a_spec, a_spec, lmu_spec,
-                  b_spec, b_spec, b_spec, lnu_spec],
-                 m, n, k, ms, bm, bn, bk, reconstruct, interpret)
-    return call(mh_a, ml_a, e_a, lmu, mh_b, ml_b, e_b, lnu)
+@functools.partial(jax.jit, static_argnames=("ms", "blocks", "interpret"))
+def ozmm_fused_quantize(mh, ml, e, l, *, ms: ModuliSet, blocks, interpret: bool):
+    """Residue parts of one operand, each tile quantized once. Inputs: the
+    operand's sign-folded raw frame (int32 (rows, cols) triple,
+    ops.decompose_raw) and its pairing scale exponents, ``l`` (rows, 1)
+    for A's rows or (1, cols) for B's columns. Dims must be multiples of
+    ``blocks`` = (br, bc) (ops pads). Returns the ``common.stack_parts``
+    layout that ozmm_fused_parts takes: fp8 families (hi, lo, hs) e4m3
+    stacks (N, rows, cols), int8 one int8 stack."""
+    rows, cols = mh.shape
+    br, bc = blocks
+    assert rows % br == 0 and cols % bc == 0 and l.shape in ((rows, 1), (1, cols)), \
+        (mh.shape, l.shape, blocks)
+    frame_spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
+    if l.shape == (rows, 1):
+        l_spec = pl.BlockSpec((br, 1), lambda i, j: (i, _I0))
+    else:
+        l_spec = pl.BlockSpec((1, bc), lambda i, j: (_I0, j))
+    n_out, dtype = (1, jnp.int8) if ms.family == "int8" else (3, E4M3)
+    out = pl.pallas_call(
+        functools.partial(_kernel_quantize, ms=ms),
+        grid=(rows // br, cols // bc),
+        in_specs=[frame_spec] * 3 + [l_spec],
+        out_specs=[pl.BlockSpec((ms.n, br, bc), lambda i, j: (_I0, i, j))] * n_out,
+        out_shape=[jax.ShapeDtypeStruct((ms.n, rows, cols), dtype)] * n_out,
+        interpret=interpret,
+    )(mh, ml, e, l)
+    return out[0] if ms.family == "int8" else tuple(out)
 
 
 @functools.partial(jax.jit, static_argnames=("ms", "bm", "bn", "bk",
@@ -291,7 +298,10 @@ def ozmm_fused_parts(sa, sb, lmu, lnu, *, ms: ModuliSet, bm: int, bn: int,
                      bk: int, reconstruct: str, interpret: bool):
     """Fused MMA + reconstruct from stacked residue parts (common.stack_parts
     layout): fp8 families take ((hi, lo, hs), ...) e4m3 stacks (N, m, k) /
-    (N, k, n); int8 takes single int8 stacks. lmu/lnu as in ozmm_fused_raw."""
+    (N, k, n); int8 takes single int8 stacks. lmu (m, 1) / lnu (1, n) are
+    the pairing scale exponents. Dims must be multiples of the block shape
+    (ops pads). Returns the f64 product (``reconstruct="onchip"``) or the
+    int16 Garner digit stack (N, m, n) (``"xla"``)."""
     if ms.family == "int8":
         m, k = sa.shape[1:]
         n = sb.shape[2]

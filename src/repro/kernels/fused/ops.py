@@ -5,8 +5,8 @@ optional XLA digit-combine epilogue.
 Entry points mirror the phase-split pipeline:
 
 * ``ozmm_pallas_fused(a, b, ...)`` — plain operands; scaling (fast or
-  accurate) runs in XLA, everything after the exponent frames runs in the
-  one fused kernel.
+  accurate) runs in XLA; after the exponent frames, one quantize kernel per
+  operand and the fused MMA + reconstruct kernel.
 * ``ozmm_pallas_fused_prepared(qa, qb, ...)`` — core.plan operands; fast
   mode streams the plans' cached residue digits through the fused
   MMA+reconstruct epilogue, accurate mode re-enters the raw-frame path with
@@ -32,7 +32,7 @@ from repro.core.moduli import DEFAULT_NUM_MODULI, ModuliSet, make_moduli_set
 from repro.core.plan import QuantizedMatrix
 
 from ..common import resolve_interpret, resolve_reconstruct, stack_parts
-from .kernel import MANT_SPLIT, ozmm_fused_parts, ozmm_fused_raw
+from .kernel import MANT_SPLIT, ozmm_fused_parts, ozmm_fused_quantize
 
 #: Env override for the block-size table: "bm,bn,bk" (read per call; the
 #: kwarg ``blocks=`` wins over the env, the env wins over the table).
@@ -144,24 +144,40 @@ def _epilogue(out, m, n, ms, lmu, lnu, reconstruct):
     return crt.reconstruct(out[:, :m, :n], ms, lmu, lnu)
 
 
-@functools.partial(jax.jit, static_argnames=("ms", "blocks", "reconstruct",
-                                             "interpret"))
-def _fused_from_frames(a, lmu, b, lnu, *, ms: ModuliSet, blocks,
-                       reconstruct: str, interpret: bool) -> jax.Array:
-    """Raw-frame fused path: decompose both operands (XLA), pad, one
-    pallas_call, epilogue."""
-    (m, k), n = a.shape, b.shape[1]
+def _products(sa, lmu, sb, lnu, m, n, *, ms: ModuliSet, blocks,
+              reconstruct: str, interpret: bool) -> jax.Array:
+    """Residue-part stacks (zero-padded here to block multiples) -> the fused
+    MMA + reconstruct kernel -> epilogue, cropped to (m, n)."""
     bm, bn, bk = blocks
-    fa = tuple(_pad2(v, bm, bk) for v in decompose_raw(a))
-    fb = tuple(_pad2(v, bk, bn) for v in decompose_raw(b))
+    pa = jax.tree.map(lambda v: _pad3(v, bm, bk), sa)
+    pb = jax.tree.map(lambda v: _pad3(v, bk, bn), sb)
     lmu_p = _pad2(lmu[:, None], bm, 1)
     lnu_p = _pad2(lnu[None, :], 1, bn)
     # Scoped here, outside the kernel's jit: a scope inside it would rename
     # the kernel's HLO instruction.
     with jax.named_scope("ozmm.products"):
-        out = ozmm_fused_raw(*fa, lmu_p, *fb, lnu_p, ms=ms, bm=bm, bn=bn,
-                             bk=bk, reconstruct=reconstruct, interpret=interpret)
+        out = ozmm_fused_parts(pa, pb, lmu_p, lnu_p, ms=ms, bm=bm, bn=bn, bk=bk,
+                               reconstruct=reconstruct, interpret=interpret)
     return _epilogue(out, m, n, ms, lmu, lnu, reconstruct)
+
+
+@functools.partial(jax.jit, static_argnames=("ms", "blocks", "reconstruct",
+                                             "interpret"))
+def _fused_from_frames(a, lmu, b, lnu, *, ms: ModuliSet, blocks,
+                       reconstruct: str, interpret: bool) -> jax.Array:
+    """Raw-frame fused path: decompose both operands (XLA), pad, quantize
+    each operand once (one pallas_call each), then the parts kernel."""
+    (m, k), n = a.shape, b.shape[1]
+    bm, bn, bk = blocks
+    fa = tuple(_pad2(v, bm, bk) for v in decompose_raw(a))
+    fb = tuple(_pad2(v, bk, bn) for v in decompose_raw(b))
+    with jax.named_scope("ozmm.quantize"):
+        sa = ozmm_fused_quantize(*fa, _pad2(lmu[:, None], bm, 1), ms=ms,
+                                 blocks=(bm, bk), interpret=interpret)
+        sb = ozmm_fused_quantize(*fb, _pad2(lnu[None, :], 1, bn), ms=ms,
+                                 blocks=(bk, bn), interpret=interpret)
+    return _products(sa, lmu, sb, lnu, m, n, ms=ms, blocks=blocks,
+                     reconstruct=reconstruct, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("ms", "blocks", "reconstruct",
@@ -170,21 +186,9 @@ def _fused_from_parts(sa, lmu, sb, lnu, *, ms: ModuliSet, blocks,
                       reconstruct: str, interpret: bool) -> jax.Array:
     """Prepared fast-mode path: cached residue-part stacks straight into the
     fused MMA + reconstruct epilogue."""
-    if ms.family == "int8":
-        (m, k), n = sa.shape[1:], sb.shape[2]
-    else:
-        (m, k), n = sa[0].shape[1:], sb[0].shape[2]
-    bm, bn, bk = blocks
-    pa = (_pad3(sa, bm, bk) if ms.family == "int8"
-          else tuple(_pad3(v, bm, bk) for v in sa))
-    pb = (_pad3(sb, bk, bn) if ms.family == "int8"
-          else tuple(_pad3(v, bk, bn) for v in sb))
-    lmu_p = _pad2(lmu[:, None], bm, 1)
-    lnu_p = _pad2(lnu[None, :], 1, bn)
-    with jax.named_scope("ozmm.products"):
-        out = ozmm_fused_parts(pa, pb, lmu_p, lnu_p, ms=ms, bm=bm, bn=bn, bk=bk,
-                               reconstruct=reconstruct, interpret=interpret)
-    return _epilogue(out, m, n, ms, lmu, lnu, reconstruct)
+    m, n = lmu.shape[0], lnu.shape[0]
+    return _products(sa, lmu, sb, lnu, m, n, ms=ms, blocks=blocks,
+                     reconstruct=reconstruct, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("ms", "mode", "blocks",
@@ -207,7 +211,7 @@ def ozmm_pallas_fused(
     reconstruct: str | None = None,
     blocks=None,
 ) -> jax.Array:
-    """Single-kernel emulated FP64 matmul (the EmuGEMM-style fused schedule;
+    """Fused emulated FP64 matmul (the EmuGEMM-style fused schedule;
     kernel.py). Bitwise-equal to ``core.ozaki2.ozmm_ozaki2`` / ``ozmm_pallas``
     by construction. Supports (..., m, k) @ (..., k, n) with matching leading
     batch dims (vmapped, like core ``ozmm``); any m/n/k (zero-pad + crop).

@@ -61,7 +61,7 @@ class PrecisionPolicy:
     operating point; ``backend`` picks the executor (``"core"`` jnp path,
     ``"pallas"`` kernel path, ``"auto"`` = the fused kernels on TPU for
     Ozaki-II schemes, core elsewhere), ``fused`` selects between the
-    single-kernel fused schedule (default; kernels.fused) and the
+    fused schedule (default; kernels.fused) and the
     phase-split pipeline (``+unfused``; kernels.pipeline) when the pallas
     backend runs, ``interpret`` forces/disables the Pallas interpreter
     (None = resolve per backend), and ``cache_plans`` gates long-lived

@@ -1,4 +1,4 @@
-"""Fused single-kernel emulated GEMM (kernels.fused): bitwise parity vs the
+"""Fused emulated GEMM (kernels.fused): bitwise parity vs the
 core path across families/moduli/modes, prepared-plan interchange, arbitrary
 (prime-ish) shapes through the pad/crop wrappers, block-size selection, and
 the +pallas/+unfused routing + guard messages."""
@@ -12,10 +12,11 @@ from repro.core import ozmm
 from repro.core.gemm import _resolve_backend
 from repro.core.moduli import DEFAULT_NUM_MODULI, make_moduli_set
 from repro.core.ozaki2 import ozmm_ozaki2
-from repro.core.plan import ozmm_prepared, quantize_matrix
-from repro.kernels import (ozmm_pallas_fused, ozmm_pallas_fused_prepared,
-                           select_blocks)
-from repro.kernels.fused.ops import BLOCKS_ENV
+from repro.core.plan import ozmm_prepared, pair_scales, quantize_matrix
+from repro.kernels import (decompose_raw, ozmm_fused_quantize,
+                           ozmm_pallas_fused, ozmm_pallas_fused_prepared,
+                           select_blocks, stack_parts)
+from repro.kernels.fused.ops import BLOCKS_ENV, _pad2
 from repro.precision import PrecisionPolicy, parse_policy
 from repro.testing import lognormal_matrix
 
@@ -93,6 +94,33 @@ def test_fused_prepared_interchange(rng, family, mode):
         got2 = ozmm_pallas_fused_prepared(qa.drop_source(), qb.drop_source(),
                                           interpret=True, blocks=BLOCKS)
         np.testing.assert_array_equal(np.asarray(got2), np.asarray(core))
+
+
+@pytest.mark.parametrize("family", ["fp8-hybrid", "fp8-karatsuba", "int8"])
+@pytest.mark.parametrize("mode", ["fast", "accurate"])
+@pytest.mark.parametrize("shape", [(32, 64, 32), (50, 70, 30)])
+def test_quantize_kernel_bitwise_vs_core_parts(rng, family, mode, shape):
+    """The quantize pre-pass writes each operand's residue parts under the
+    pairing exponents bitwise as the core plan's parts in the stacked
+    kernel layout; the padding of the prime-ish shape holds only zeros."""
+    m, k, n = shape
+    a, b = _operands(rng, m, k, n)
+    ms = make_moduli_set(family, DEFAULT_NUM_MODULI[family])
+    lmu, lnu, parts_a, parts_b = pair_scales(
+        quantize_matrix(a, "lhs", ms, mode=mode),
+        quantize_matrix(b, "rhs", ms, mode=mode))
+    bm, bn, bk = BLOCKS
+    for x, l, parts, (br, bc) in ((a, _pad2(lmu[:, None], bm, 1), parts_a, (bm, bk)),
+                                  (b, _pad2(lnu[None, :], 1, bn), parts_b, (bk, bn))):
+        rows, cols = x.shape
+        frame = [_pad2(v, br, bc) for v in decompose_raw(x)]
+        got = ozmm_fused_quantize(*frame, l, ms=ms, blocks=(br, bc),
+                                  interpret=True)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(stack_parts(parts, ms))):
+            want = np.zeros(g.shape, g.dtype)
+            want[:, :rows, :cols] = np.asarray(w)
+            np.testing.assert_array_equal(np.asarray(g).view(np.uint8),
+                                          want.view(np.uint8))
 
 
 @pytest.mark.parametrize("shape", [(250, 94, 61), (127, 33, 129), (1, 5, 3)])

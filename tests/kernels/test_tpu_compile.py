@@ -7,6 +7,7 @@ The topology is described inside a module-scoped fixture (never at import):
 only the worker that runs these tests loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,8 +16,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.gemm import _ozmm_2d_raw
 from repro.core.moduli import DEFAULT_NUM_MODULI, make_moduli_set
-from repro.kernels.fused.kernel import ozmm_fused_parts, ozmm_fused_raw
-from repro.kernels.fused.ops import BLOCK_TABLE
+from repro.kernels.fused.kernel import ozmm_fused_parts, ozmm_fused_quantize
+from repro.kernels.fused.ops import BLOCK_TABLE, _fused_from_frames, ozmm_pallas_fused
 
 jax.config.update("jax_enable_x64", True)
 
@@ -24,6 +25,8 @@ FAMILIES = ("fp8-hybrid", "fp8-karatsuba", "int8")
 SCHEME = {"fp8-hybrid": "ozaki2-fp8", "fp8-karatsuba": "ozaki2-karatsuba",
           "int8": "ozaki2-int8"}
 M = 2048
+#: The benchmark's square size: a whole operand's parts are quantized at once.
+M_FULL = 8192
 
 
 @pytest.fixture(scope="module")
@@ -61,15 +64,52 @@ def _has_kernel(compiled) -> bool:
 
 
 @pytest.mark.parametrize("family", FAMILIES)
-def test_fused_raw_compiles_for_v5e(one_chip, family):
+def test_fused_quantize_compiles_for_v5e(one_chip, family):
     ms, kw = _kw(family)
-    frame = _spec(one_chip, (M, M), jnp.int32)
-    lmu = _spec(one_chip, (M, 1), jnp.int32)
-    lnu = _spec(one_chip, (1, M), jnp.int32)
-    compiled = jax.jit(lambda *x: ozmm_fused_raw(*x, **kw)).lower(
-        frame, frame, frame, lmu, frame, frame, frame, lnu).compile()
+    frame = _spec(one_chip, (M_FULL, M_FULL), jnp.int32)
+    lmu = _spec(one_chip, (M_FULL, 1), jnp.int32)
+    compiled = jax.jit(lambda *x: ozmm_fused_quantize(
+        *x, ms=ms, blocks=(kw["bm"], kw["bk"]), interpret=False)).lower(
+        frame, frame, frame, lmu).compile()
     assert _has_kernel(compiled)
-    assert compiled.memory_analysis().output_size_in_bytes == ms.n * M * M * 2
+    n_stacks, dtype = (1, jnp.int8) if family == "int8" else (3, jnp.float8_e4m3fn)
+    assert ([(o.shape, o.dtype) for o in jax.tree.leaves(compiled.out_info)]
+            == [((ms.n, M_FULL, M_FULL), dtype)] * n_stacks)
+
+
+def test_fused_from_frames_compiles_for_v5e(one_chip):
+    """The whole raw-frame route at 8192^3, fp8-hybrid at 12 moduli: both
+    operands' parts (2.4 GB each) fit the chip's 16 GB beside the digits."""
+    ms, kw = _kw("fp8-hybrid")
+    x = _spec(one_chip, (M_FULL, M_FULL), jnp.float64)
+    l = _spec(one_chip, (M_FULL,), jnp.int32)
+    compiled = jax.jit(lambda a, lmu, b, lnu: _fused_from_frames(
+        a, lmu, b, lnu, ms=ms, blocks=(kw["bm"], kw["bn"], kw["bk"]),
+        reconstruct="xla", interpret=False)).lower(x, l, x, l).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == M_FULL * M_FULL * 8
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16e9
+
+
+def test_fused_route_kernels_in_compiled_hlo(one_chip):
+    """An accurate ozmm on the fused route compiles to two quantize kernels
+    (one per operand) under the ``ozmm.quantize`` scope and one parts kernel,
+    each HLO instruction named by its kernel's jit, not by a scope (the
+    name a device trace's ops and breakdowns are read by)."""
+    a = _spec(one_chip, (512, 512), jnp.float64)
+    b = _spec(one_chip, (512, 256), jnp.float64)
+    hlo = jax.jit(lambda x, y: ozmm_pallas_fused(
+        x, y, mode="accurate", interpret=False, reconstruct="xla",
+        blocks=(128, 128, 128))).lower(a, b).compile().as_text()
+    kernels = [ln for ln in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    names = [re.match(r"\s*(?:ROOT )?%([\w.]+) =", ln).group(1) for ln in kernels]
+    assert sorted(n.split(".")[0] for n in names) == [
+        "ozmm_fused_parts", "ozmm_fused_quantize", "ozmm_fused_quantize"], names
+    for name, line in zip(names, kernels):
+        (op_name,) = re.findall(r'op_name="([^"]*)"', line)
+        scope = "quantize" if name.startswith("ozmm_fused_quantize") else "products"
+        assert f"/ozmm.{scope}/jit({name.split('.')[0]})/" in op_name, op_name
 
 
 @pytest.mark.parametrize("family", FAMILIES)
